@@ -12,6 +12,8 @@ import os
 import sys
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 BENCHES = [
     ("cost", "Fig. 10 interconnect cost"),
     ("dedicated", "Fig. 11 dedicated 128-server cluster"),
@@ -39,6 +41,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="reduced sizes for CI (benches that support it)")
     args = ap.parse_args()
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
 
     os.makedirs(args.out, exist_ok=True)

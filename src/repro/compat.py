@@ -1,8 +1,4 @@
-"""Version-compatibility shims for the jax surface the repo touches.
-
-jax moved ``shard_map`` out of ``jax.experimental`` (and renamed its
-replication-check kwarg ``check_rep`` -> ``check_vma``) across 0.4.x -> 0.5+.
-``shard_map_compat`` papers over both so callers write one code path.
+"""Process-wide JAX numerics for the planner backend.
 
 :func:`ensure_x64` pins 64-bit JAX arithmetic for the planner backend
 (:mod:`repro.core.planeval_jax`): the NumPy plan evaluator is float64, and
@@ -12,9 +8,7 @@ JAX-vs-NumPy equivalence tolerances drift with the platform default.
 
 from __future__ import annotations
 
-import inspect
 import os
-from functools import lru_cache
 
 # Truthiness table for JAX_ENABLE_X64-style env switches.
 _FALSY = {"0", "false", "False", "FALSE", ""}
@@ -38,36 +32,3 @@ def ensure_x64(enable: bool | None = None) -> bool:
         enable = True if env is None else env not in _FALSY
     jax.config.update("jax_enable_x64", bool(enable))
     return bool(jax.config.jax_enable_x64)
-
-
-@lru_cache(maxsize=1)
-def _resolve_shard_map():
-    try:
-        from jax import shard_map as sm  # jax >= 0.6
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm  # jax 0.4.x/0.5.x
-    params = inspect.signature(sm).parameters
-    if "check_vma" in params:
-        check_kwarg = "check_vma"
-    elif "check_rep" in params:
-        check_kwarg = "check_rep"
-    else:
-        check_kwarg = None
-    return sm, check_kwarg
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_replication=False):
-    """``shard_map`` with the replication check toggled portably."""
-    sm, check_kwarg = _resolve_shard_map()
-    kwargs = {check_kwarg: check_replication} if check_kwarg else {}
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a mapped mesh axis (``lax.axis_size`` only exists on
-    newer jax; ``psum(1, axis)`` is the portable spelling and stays static)."""
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)

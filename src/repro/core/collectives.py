@@ -24,7 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import axis_size
+# TPU vector lane width: blocks of this many columns tile without padding.
+_LANES = 128
 
 
 def _mod_inverse(p: int, n: int) -> int:
@@ -38,26 +39,47 @@ def _ring_perm(n: int, p: int) -> list[tuple[int, int]]:
     return [(i, (i + p) % n) for i in range(n)]
 
 
-def ring_all_reduce(x: jax.Array, axis_name: str, p: int = 1) -> jax.Array:
-    """Ring AllReduce over the stride-``p`` permutation of ``axis_name``.
+def _blocks(x: jax.Array, k: int) -> jax.Array:
+    """``x`` zero-padded and cut into ``k`` equal 2-D chunks,
+    ``(k, rows, cols)``, for the collectives to move.
 
-    Must be called inside ``shard_map``.  Equivalent to ``lax.psum(x, axis)``.
-    """
-    n = axis_size(axis_name)
-    if n == 1:
-        return x
+    Whole rows of the last axis where there are at least 8 per chunk:
+    slicing rows keeps the TPU's (8, 128)-tiled layout, so no relayout
+    copy meets a collective.  Otherwise the flattened values in blocks of
+    128 columns.  The TPU compiler takes time in proportion to the length
+    of a permuted 1-D slice, and to the size of a relayout feeding
+    several trees (minutes for an embedding-sized gradient)."""
+    if x.ndim >= 2 and x.size // x.shape[-1] >= 8 * k:
+        m = x.reshape(-1, x.shape[-1])
+        rows = -(-m.shape[0] // (8 * k)) * 8
+        pad = k * rows - m.shape[0]
+        if pad:
+            m = jnp.pad(m, ((0, pad), (0, 0)))
+        return m.reshape(k, rows, m.shape[1])
+    flat = x.reshape(-1)
+    rows = -(-flat.size // (k * _LANES))
+    pad = k * rows * _LANES - flat.size
+    if pad:
+        flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
+    return flat.reshape(k, rows, _LANES)
+
+
+def _unblocks(blocks: jax.Array, like: jax.Array) -> jax.Array:
+    """Inverse of :func:`_blocks`: drop the padding, restore the shape."""
+    if like.ndim >= 2 and blocks.shape[-1] == like.shape[-1]:
+        cols = like.shape[-1]
+        return blocks.reshape(-1, cols)[: like.size // cols].reshape(like.shape)
+    return blocks.reshape(-1)[: like.size].reshape(like.shape)
+
+
+def _ring_rows(acc: jax.Array, axis_name: str, p: int) -> jax.Array:
+    """Ring AllReduce of ``acc`` (n, ...) whose leading axis holds the n
+    segments, over the stride-``p`` permutation of ``axis_name``."""
+    n = acc.shape[0]
     inv_p = _mod_inverse(p, n)
     perm = _ring_perm(n, p)
     # Position of this device along the ring: ring visits (j * p) % n.
     pos = (lax.axis_index(axis_name) * inv_p) % n
-
-    shape = x.shape
-    flat = x.reshape(-1)
-    seg = -(-flat.size // n)  # ceil
-    pad = seg * n - flat.size
-    if pad:
-        flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
-    acc = flat.reshape(n, seg)
 
     def seg_at(arr, idx):
         return lax.dynamic_index_in_dim(arr, idx % n, axis=0, keepdims=False)
@@ -79,11 +101,18 @@ def ring_all_reduce(x: jax.Array, axis_name: str, p: int = 1) -> jax.Array:
         sent = seg_at(acc, send_idx)
         received = lax.ppermute(sent, axis_name, perm)
         acc = lax.dynamic_update_index_in_dim(acc, received, recv_idx % n, axis=0)
+    return acc
 
-    out = acc.reshape(-1)
-    if pad:
-        out = out[: flat.size - pad]
-    return out.reshape(shape)
+
+def ring_all_reduce(x: jax.Array, axis_name: str, p: int = 1) -> jax.Array:
+    """Ring AllReduce over the stride-``p`` permutation of ``axis_name``.
+
+    Must be called inside ``shard_map``.  Equivalent to ``lax.psum(x, axis)``.
+    """
+    n = lax.axis_size(axis_name)
+    if n == 1:
+        return x
+    return _unblocks(_ring_rows(_blocks(x, n), axis_name, p), x)
 
 
 def ring_reduce_scatter(x: jax.Array, axis_name: str, p: int = 1) -> jax.Array:
@@ -91,7 +120,7 @@ def ring_reduce_scatter(x: jax.Array, axis_name: str, p: int = 1) -> jax.Array:
     (n * chunk,) flattened; returns this device's reduced chunk, ordered so
     that ``ring_all_gather`` reassembles ``psum(x)``.  Device at ring position
     j returns segment (j+1) % n mapped back to device order."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x.reshape(-1)
     inv_p = _mod_inverse(p, n)
@@ -136,21 +165,15 @@ def multi_ring_all_reduce(
     if r == 1:
         return ring_all_reduce(x, axis_name, strides[0])
 
-    shape = x.shape
-    flat = x.reshape(-1)
-    chunk = -(-flat.size // r)
-    pad = chunk * r - flat.size
-    if pad:
-        flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
-    chunks = flat.reshape(r, chunk)
-
+    n = lax.axis_size(axis_name)
+    if n == 1:
+        return x
+    blocks = _blocks(x, r * n)
+    chunks = blocks.reshape(r, n, *blocks.shape[1:])
     reduced = [
-        ring_all_reduce(chunks[i], axis_name, strides[i]) for i in range(r)
+        _ring_rows(chunks[i], axis_name, strides[i]) for i in range(r)
     ]
-    out = jnp.concatenate(reduced).reshape(-1)
-    if pad:
-        out = out[: flat.size - pad]
-    return out.reshape(shape)
+    return _unblocks(jnp.stack(reduced), x)
 
 
 def recursive_hd_all_reduce(x: jax.Array, axis_name: str) -> jax.Array:
@@ -163,7 +186,7 @@ def recursive_hd_all_reduce(x: jax.Array, axis_name: str) -> jax.Array:
     form.  Equivalent to ``lax.psum(x, axis)`` (exact for integer inputs:
     every addition is a disjoint pairwise tree).
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     if n < 2 or n & (n - 1):
@@ -172,13 +195,7 @@ def recursive_hd_all_reduce(x: jax.Array, axis_name: str) -> jax.Array:
         )
     me = lax.axis_index(axis_name)
 
-    shape = x.shape
-    flat = x.reshape(-1)
-    seg = -(-flat.size // n)  # ceil
-    pad = seg * n - flat.size
-    if pad:
-        flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
-    acc = flat.reshape(n, seg)
+    acc = _blocks(x, n)
 
     # Recursive halving: the live block [lo, lo + 2d) splits at each round;
     # the kept half accumulates the partner's complementary half.
@@ -208,10 +225,7 @@ def recursive_hd_all_reduce(x: jax.Array, axis_name: str) -> jax.Array:
         lo = jnp.minimum(lo, lo ^ d)
         d *= 2
 
-    out = acc.reshape(-1)
-    if pad:
-        out = out[: flat.size - pad]
-    return out.reshape(shape)
+    return _unblocks(acc, x)
 
 
 def _tree_all_reduce(x: jax.Array, axis_name: str, order: list[int]) -> jax.Array:
@@ -275,26 +289,16 @@ def multi_tree_all_reduce(
         raise ValueError("need at least one tree stride")
     from .totient import ring_order
 
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     orders = [[int(v) for v in ring_order(n, p)] for p in strides]
 
-    shape = x.shape
-    flat = x.reshape(-1)
-    chunk = -(-flat.size // r)
-    pad = chunk * r - flat.size
-    if pad:
-        flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
-    chunks = flat.reshape(r, chunk)
-
+    chunks = _blocks(x, r)
     reduced = [
         _tree_all_reduce(chunks[i], axis_name, orders[i]) for i in range(r)
     ]
-    out = jnp.concatenate(reduced).reshape(-1)
-    if pad:
-        out = out[: flat.size - pad]
-    return out.reshape(shape)
+    return _unblocks(jnp.stack(reduced), x)
 
 
 def topoopt_psum_fn(
@@ -343,7 +347,7 @@ def all_to_all_ring(x: jax.Array, axis_name: str, p: int = 1) -> jax.Array:
     around a stride-``p`` ring — the host-based-forwarding analogue for EP
     traffic on a direct-connect fabric.  ``x``: (n, ...) per-destination data;
     returns (n, ...) per-source data.  Equivalent to lax.all_to_all."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     me = lax.axis_index(axis_name)

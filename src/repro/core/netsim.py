@@ -182,11 +182,9 @@ def _routing_with_fallback(topo: Topology, flows) -> "RoutingTable":
     if not missing_any:
         return routing
     if cache is None:
-        from .routing import RoutingTable
-
         cache = {}
         topo._sp_cache = cache
-        topo._merged_routing = RoutingTable(routes=dict(routing.routes))
+        topo._merged_routing = routing.overlay()
     merged = topo._merged_routing
     if need:
         import networkx as nx
@@ -201,7 +199,7 @@ def _routing_with_fallback(topo: Topology, flows) -> "RoutingTable":
             try:
                 path = tuple(nx.shortest_path(simple, s, t))
                 merged.add(s, t, path)
-                cache[(s, t)] = merged.routes[(s, t)]
+                cache[(s, t)] = merged.get(s, t)
             except (nx.NetworkXNoPath, nx.NodeNotFound):
                 cache[(s, t)] = []
     return merged

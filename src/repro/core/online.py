@@ -63,6 +63,7 @@ fragmented churn trace.
 
 from __future__ import annotations
 
+import sys
 from collections import Counter
 from dataclasses import dataclass, field
 
@@ -120,6 +121,16 @@ def edge_churn(old: Topology, new: Topology) -> int:
     c_old = Counter(old.graph.edges())
     c_new = Counter(new.graph.edges())
     return int(sum((c_new - c_old).values()))
+
+
+def _is_device_fault(exc: Exception) -> bool:
+    """A JAX compile, lowering or device runtime failure (as opposed to a
+    search that failed on its inputs).  Only a process that has imported
+    JAX can raise one."""
+    jax = sys.modules.get("jax")
+    return jax is not None and isinstance(
+        exc, (jax.errors.JaxRuntimeError, jax.errors.JAXTypeError)
+    )
 
 
 @dataclass(frozen=True)
@@ -691,7 +702,8 @@ class ReoptController(ScenarioObserver):
         or ``None`` after exhausting every attempt — the caller then keeps
         the last-known-good plan (+ §7 repair) and the controller backs off
         exponentially, so a fault storm cannot wedge it in a replan-crash
-        loop."""
+        loop.  A JAX compile or runtime error propagates instead: it means
+        the device path is broken, which no other seed repairs."""
         import time as _time
 
         deadline = self.policy.replan_deadline
@@ -701,7 +713,9 @@ class ReoptController(ScenarioObserver):
             t0 = _time.perf_counter()
             try:
                 res = self._run_optimizer(warm=True)
-            except Exception:
+            except Exception as exc:
+                if _is_device_fault(exc):
+                    raise
                 self.n_optimizer_errors += 1
                 self.log.append(ReplanRecord(
                     time=now, trigger=f"{trigger}:error", replanned=False))

@@ -59,7 +59,6 @@ from .planeval import PlanEvaluator, plan_evaluator
 __all__ = [
     "JAX_EQUIV_RTOL",
     "DEFAULT_TEMPER_LADDER",
-    "have_jax",
     "pack_demand",
     "JaxPlanEvaluator",
     "jax_plan_evaluator",
@@ -140,15 +139,6 @@ def _require_jax():
 
         _jax = jax
     return _jax
-
-
-def have_jax() -> bool:
-    """True when the JAX backend can run (import succeeds)."""
-    try:
-        _require_jax()
-        return True
-    except Exception:  # pragma: no cover - jax is baked into the image
-        return False
 
 
 # ---------------------------------------------------------------------------

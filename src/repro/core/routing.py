@@ -5,7 +5,7 @@ k-shortest-path for MP transfers, and host-based-forwarding accounting
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
 import networkx as nx
 import numpy as np
@@ -22,18 +22,77 @@ class Route:
         return len(self.path) - 1
 
 
-@dataclass
 class RoutingTable:
     """Routes between node pairs.  Multiple routes per pair allowed
-    (host-based forwarding load-balances across them)."""
+    (host-based forwarding load-balances across them).
 
-    routes: dict[tuple[int, int], list[Route]] = field(default_factory=dict)
+    A table may rest on a lazy source: the coin-change routes of AllReduce
+    rings (:meth:`add_rings`), or another table seen through dead links
+    (:meth:`rerouted`) or beneath routes of its own (:meth:`overlay`).
+    :meth:`get` builds a pair's routes from the source when first asked,
+    and reading :attr:`routes` spells the whole table out, in the order an
+    eager build produces.  Pricing reads a few pairs of a table whose eager
+    build is O(n^2 x path length): a fleet's zero-byte connectivity ring
+    alone spells out ~4e7 hops at 432 servers.
+    """
+
+    def __init__(self, routes: dict | None = None):
+        self._routes: dict[tuple[int, int], list[Route]] = (
+            {} if routes is None else routes
+        )
+        self._source = None
+        self._memo: dict[tuple[int, int], list[Route]] = {}
+
+    @property
+    def routes(self) -> dict[tuple[int, int], list[Route]]:
+        if self._source is not None:
+            full = dict(self._source.items())
+            full.update(self._routes)
+            self._routes, self._source, self._memo = full, None, {}
+        return self._routes
 
     def add(self, src: int, dst: int, path: tuple[int, ...]) -> None:
-        self.routes.setdefault((src, dst), []).append(Route(path=path))
+        pair = (src, dst)
+        if pair not in self._routes and self._source is not None:
+            self._routes[pair] = list(self.get(src, dst))
+        self._routes.setdefault(pair, []).append(Route(path=path))
+
+    def set(self, src: int, dst: int, routes: list[Route]) -> None:
+        self._routes[(src, dst)] = routes
 
     def get(self, src: int, dst: int) -> list[Route]:
-        return self.routes.get((src, dst), [])
+        pair = (src, dst)
+        rs = self._routes.get(pair)
+        if rs is None and self._source is not None:
+            rs = self._memo.get(pair)
+            if rs is None:
+                rs = self._memo[pair] = self._source.get(pair) or []
+        return rs if rs is not None else []
+
+    def add_rings(self, members: tuple[int, ...], strides: list[int]) -> None:
+        """Route every ordered pair of an AllReduce group over its stride
+        rings (coin-change in group-local index space, App. E.3).  A pair
+        in several groups takes the last group's route."""
+        if self._source is None and not self._routes:
+            self._source = _RingRoutes()
+        if not isinstance(self._source, _RingRoutes) or self._routes:
+            raise ValueError("add_rings needs a table of ring routes only")
+        self._source.add(tuple(members), strides)
+
+    def rerouted(self, removed: set, graph: nx.MultiDiGraph) -> "RoutingTable":
+        """This table after the ``removed`` directed links died: routes
+        that avoid them are kept, the rest re-pathed by shortest path on
+        ``graph``; pairs left unreachable drop out."""
+        table = RoutingTable()
+        table._source = _Rerouted(self, set(removed), nx.DiGraph(graph))
+        return table
+
+    def overlay(self) -> "RoutingTable":
+        """A table that reads through to this one; routes added to it stay
+        its own."""
+        table = RoutingTable()
+        table._source = _Rerouted(self, set(), None)
+        return table
 
 
 def coin_change_mod(n: int, strides: list[int]) -> dict[int, list[int]]:
@@ -62,19 +121,81 @@ def coin_change_mod(n: int, strides: list[int]) -> dict[int, list[int]]:
     return bt
 
 
+def _coin_path(members, bt, i: int, j: int) -> tuple[int, ...]:
+    n = len(members)
+    path = [i]
+    for c in bt[(j - i) % n]:
+        path.append((path[-1] + c) % n)
+    return tuple(members[v] for v in path)
+
+
+class _RingRoutes:
+    """Lazy source of AllReduce ring routes, one entry per group."""
+
+    def __init__(self):
+        self.groups: list[tuple[tuple[int, ...], dict[int, int], dict]] = []
+
+    def add(self, members: tuple[int, ...], strides: list[int]) -> None:
+        index = {v: i for i, v in enumerate(members)}
+        self.groups.append((members, index, coin_change_mod(len(members), strides)))
+
+    def get(self, pair):
+        src, dst = pair
+        for members, index, bt in reversed(self.groups):
+            i, j = index.get(src), index.get(dst)
+            if i is not None and j is not None and i != j:
+                return [Route(path=_coin_path(members, bt, i, j))]
+        return None
+
+    def items(self):
+        for members, _, bt in self.groups:
+            n = len(members)
+            for i in range(n):
+                for m in bt:
+                    j = (i + m) % n
+                    yield (members[i], members[j]), [
+                        Route(path=_coin_path(members, bt, i, j))
+                    ]
+
+
+class _Rerouted:
+    """Lazy source: ``base`` with routes crossing ``removed`` links
+    re-pathed on ``simple`` (no links removed: ``base`` as it is)."""
+
+    def __init__(self, base: RoutingTable, removed: set, simple):
+        self.base, self.removed, self.simple = base, removed, simple
+
+    def _repath(self, pair, rs):
+        if not self.removed:
+            return rs
+        keep = [
+            r for r in rs
+            if not any(hop in self.removed
+                       for hop in zip(r.path[:-1], r.path[1:]))
+        ]
+        if keep:
+            return keep
+        try:
+            return [Route(path=tuple(nx.shortest_path(self.simple, *pair)))]
+        except (nx.NetworkXNoPath, nx.NodeNotFound):
+            return None
+
+    def get(self, pair):
+        rs = self.base.get(*pair)
+        return self._repath(pair, rs) if rs else None
+
+    def items(self):
+        for pair, rs in self.base.routes.items():
+            new = self._repath(pair, rs)
+            if new is not None:
+                yield pair, new
+
+
 def allreduce_routes(members: tuple[int, ...], strides: list[int]) -> RoutingTable:
     """Routes for every ordered pair of an AllReduce group over its stride
     rings (coin-change in group-local index space, App. E.3)."""
-    n = len(members)
     table = RoutingTable()
-    bt = coin_change_mod(n, strides)
-    for i in range(n):
-        for m, coin_seq in bt.items():
-            j = (i + m) % n
-            path = [i]
-            for c in coin_seq:
-                path.append((path[-1] + c) % n)
-            table.add(members[i], members[j], tuple(members[v] for v in path))
+    table.add_rings(members, strides)
     return table
 
 

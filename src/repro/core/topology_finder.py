@@ -40,7 +40,7 @@ import networkx as nx
 import numpy as np
 
 from .demand import AllReduceGroup, TrafficDemand
-from .routing import RoutingTable, allreduce_routes, k_shortest_mp_routes
+from .routing import RoutingTable, k_shortest_mp_routes
 from .select_perms import coin_change_diameter, select_permutations
 from .totient import PermutationSet, RingPermutation, totient_perms
 
@@ -242,14 +242,13 @@ def topology_finder(
     for members, group_rings in rings.items():
         strides = [r.p for r in group_rings]
         if strides:
-            sub = allreduce_routes(members, strides)
-            routing.routes.update(sub.routes)
+            routing.add_rings(members, strides)
     mp_routes = k_shortest_mp_routes(graph, demand.mp, k=mp_route_k)
     # MP routes take priority on pairs where both exist (shorter on combined G).
     for pair, rs in mp_routes.routes.items():
-        existing = routing.routes.get(pair)
-        if existing is None or min(r.hops for r in rs) < min(r.hops for r in existing):
-            routing.routes[pair] = rs
+        existing = routing.get(*pair)
+        if not existing or min(r.hops for r in rs) < min(r.hops for r in existing):
+            routing.set(*pair, rs)
     topo.routing = routing
     return topo
 
@@ -423,30 +422,8 @@ def repair_topology(topo: Topology, failed: tuple[int, int]) -> Topology:
     # Recompute routing on the surviving graph (shortest paths for every pair
     # previously routed through a removed link — the failure AND the donated
     # MP link).
-    repaired.routing = _reroute_around(topo, g, removed)
+    repaired.routing = topo.routing.rerouted(removed, g)
     return repaired
-
-
-def _reroute_around(topo: Topology, g: nx.MultiDiGraph,
-                    removed: set) -> RoutingTable:
-    """Keep routes that avoid ``removed`` links; re-path the rest by
-    shortest path on ``g`` (drop pairs that became unreachable)."""
-    simple = nx.DiGraph(g)
-    new_routing = RoutingTable()
-    for pair, rs in topo.routing.routes.items():
-        keep = [
-            r for r in rs
-            if not any(hop in removed for hop in zip(r.path[:-1], r.path[1:]))
-        ]
-        if keep:
-            new_routing.routes[pair] = keep
-            continue
-        try:
-            path = nx.shortest_path(simple, pair[0], pair[1])
-            new_routing.add(pair[0], pair[1], tuple(path))
-        except (nx.NetworkXNoPath, nx.NodeNotFound):
-            continue
-    return new_routing
 
 
 def remove_pair(topo: Topology, pair: tuple[int, int]) -> Topology:
@@ -471,7 +448,7 @@ def remove_pair(topo: Topology, pair: tuple[int, int]) -> Topology:
         n=topo.n, degree=topo.degree, graph=g, rings=topo.rings,
         d_allreduce=topo.d_allreduce, d_mp=topo.d_mp,
     )
-    degraded.routing = _reroute_around(topo, g, removed)
+    degraded.routing = topo.routing.rerouted(removed, g)
     return degraded
 
 
@@ -486,7 +463,7 @@ def restore_pair(
     removed; they are re-added verbatim and the restored directions get
     their direct route back.  Routes that were detoured around the dead
     pair keep their detour — they are valid, just suboptimal, and the next
-    re-optimization (or :func:`_reroute_around`) tightens them.
+    re-optimization (or :meth:`RoutingTable.rerouted`) tightens them.
     """
     g = topo.graph.copy()
     for a, b, data in edges:

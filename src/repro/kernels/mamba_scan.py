@@ -29,7 +29,7 @@ def _scan_kernel(xc_ref, dt_ref, a_ref, b_ref, c_ref, dskip_ref,
         h_scr[...] = jnp.zeros_like(h_scr)
 
     a = a_ref[...]  # (blk, ST) — A matrix (negative)
-    dskip = dskip_ref[...]  # (blk,)
+    dskip = dskip_ref[0]  # (blk,)
 
     def step(t, h):
         x_t = xc_ref[0, t, :].astype(jnp.float32)  # (blk,)
@@ -72,6 +72,9 @@ def mamba_scan(
     chunk = min(chunk, L)
     assert DI % block_d == 0 and L % chunk == 0
     grid = (B, DI // block_d, L // chunk)
+    # The time loop reads and writes one row per step at a dynamic offset,
+    # which Mosaic lowers for 32-bit data only.
+    xc, dt, b, c = (v.astype(jnp.float32) for v in (xc, dt, b, c))
 
     kernel = functools.partial(_scan_kernel, chunk=chunk)
     return pl.pallas_call(
@@ -83,7 +86,7 @@ def mamba_scan(
             pl.BlockSpec((block_d, ST), lambda bi, di, ci: (di, 0)),
             pl.BlockSpec((1, chunk, ST), lambda bi, di, ci: (bi, ci, 0)),
             pl.BlockSpec((1, chunk, ST), lambda bi, di, ci: (bi, ci, 0)),
-            pl.BlockSpec((block_d,), lambda bi, di, ci: (di,)),
+            pl.BlockSpec((1, block_d), lambda bi, di, ci: (0, di)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, block_d), lambda bi, di, ci: (bi, ci, di)),
@@ -95,4 +98,4 @@ def mamba_scan(
         ],
         scratch_shapes=[pltpu.VMEM((block_d, ST), jnp.float32)],
         interpret=interpret,
-    )(xc, dt, a, b, c, d_skip)
+    )(xc, dt, a, b, c, d_skip[None, :])

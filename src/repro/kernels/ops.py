@@ -1,7 +1,8 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True on CPU (this container) and False on TPU,
-where the kernels compile to Mosaic.  The XLA fallbacks live in
+``interpret`` defaults to True on the CPU (the test suite) and False on the
+TPU, where the kernels compile to Mosaic; any other backend raises, since
+the kernels have no lowering there.  The XLA fallbacks live in
 models/layers.py; these wrappers are the TPU fast path.
 """
 
@@ -17,7 +18,12 @@ from .rglru_scan import rglru_scan
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas TPU kernels have no lowering for backend {backend!r}"
+        )
+    return backend == "cpu"
 
 
 def attention(q, k, v, causal=True, window=0, **kw):

@@ -35,7 +35,7 @@ def _lru_kernel(a_ref, b_ref, y_ref, hout_ref, h_scr, *, chunk: int):
 
     @pl.when(ci == nc - 1)
     def _finish():
-        hout_ref[0] = h_scr[...]
+        hout_ref[0, 0] = h_scr[...]
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "chunk", "interpret"))
@@ -52,9 +52,12 @@ def rglru_scan(
     chunk = min(chunk, L)
     assert D % block_d == 0 and L % chunk == 0
     grid = (B, D // block_d, L // chunk)
+    # The time loop reads and writes one row per step at a dynamic offset,
+    # which Mosaic lowers for 32-bit data only.
+    a, b = a.astype(jnp.float32), b.astype(jnp.float32)
 
     kernel = functools.partial(_lru_kernel, chunk=chunk)
-    return pl.pallas_call(
+    h_all, h_fin = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -63,12 +66,15 @@ def rglru_scan(
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, block_d), lambda bi, di, ci: (bi, ci, di)),
-            pl.BlockSpec((1, block_d), lambda bi, di, ci: (bi, di)),
+            # (1, 1, block_d): a (1, block_d) block of a (B, D) array
+            # would break the TPU's 8-row tiling for B > 1.
+            pl.BlockSpec((1, 1, block_d), lambda bi, di, ci: (bi, 0, di)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, L, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, D), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_d,), jnp.float32)],
         interpret=interpret,
     )(a, b)
+    return h_all, h_fin[:, 0]

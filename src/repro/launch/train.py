@@ -15,6 +15,7 @@ import argparse
 import jax
 
 from repro.configs.base import ShapeSpec, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import adamw, cosine, wsd
 from repro.parallel.sharding import ShardingPlan
 from repro.train.loop import train
@@ -36,6 +37,7 @@ def main() -> None:
     ap.add_argument("--remat", default="full", choices=["full", "dots", "none"])
     ap.add_argument("--fail-at", type=int, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

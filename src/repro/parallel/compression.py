@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import axis_size
 from ..core.collectives import _mod_inverse, _ring_perm
 
 
@@ -43,7 +42,7 @@ def compressed_ring_all_reduce(
 
     Per-hop requantization error is kept locally and returned as a residual
     with x's shape.  Returns (allreduced_approx, residual)."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     shape = x.shape
     if n == 1:
         return x, jnp.zeros_like(x)
@@ -114,7 +113,7 @@ class Compressor:
     def sync(self, grads, residual, axis_name: str, strides=(1,)):
         """Error-feedback compressed gradient sync.  Returns
         (mean_grads, new_residual)."""
-        n = axis_size(axis_name)
+        n = lax.axis_size(axis_name)
         strides = tuple(strides) or (1,)
         leaves, treedef = jax.tree.flatten(grads)
         res_leaves = treedef.flatten_up_to(residual)

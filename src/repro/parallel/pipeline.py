@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import axis_size
 
 
 def gpipe_forward(stage_fn, stage_params, microbatches, axis_name: str = "pipe"):
@@ -29,7 +28,7 @@ def gpipe_forward(stage_fn, stage_params, microbatches, axis_name: str = "pipe")
       stage 0 consumes it.
     Returns (M, mb, ...) outputs, valid on the LAST stage (zeros elsewhere).
     """
-    S = axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     sid = lax.axis_index(axis_name)
     M = microbatches.shape[0]
     fwd_perm = [(i, i + 1) for i in range(S - 1)]
@@ -57,8 +56,6 @@ def make_gpipe_step(stage_fn, mesh, axis_name: str = "pipe"):
     microbatches replicated in, outputs gathered from the last stage."""
     from jax.sharding import PartitionSpec as P
 
-    from ..compat import shard_map_compat
-
     S = mesh.shape[axis_name]
 
     def run(params_stacked, microbatches):
@@ -68,10 +65,10 @@ def make_gpipe_step(stage_fn, mesh, axis_name: str = "pipe"):
         # outs are zero except on the last stage: psum broadcasts them.
         return lax.psum(outs, axis_name)
 
-    smapped = shard_map_compat(
+    smapped = jax.shard_map(
         run, mesh=mesh,
         in_specs=(P(axis_name), P()),
         out_specs=P(),
-        check_replication=False,
+        check_vma=False,
     )
     return jax.jit(smapped)

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +37,9 @@ class TrainResult:
     losses: list[float] = field(default_factory=list)
     straggler_steps: int = 0
     restarts: int = 0
+    # The final parameters and the last step's metrics, as device arrays.
+    params: Any = None
+    metrics: dict | None = None
 
 
 def train(
@@ -88,6 +92,7 @@ def train(
                 params, opt_state, metrics = jitted(
                     params, opt_state, batch, jnp.int32(step)
                 )
+            result.metrics = metrics
             loss = float(metrics["loss"])
             dt = time.perf_counter() - t0
             step_times.append(dt)
@@ -114,4 +119,5 @@ def train(
 
     if ckpt_dir:
         save_checkpoint(ckpt_dir, result.final_step, params, opt_state)
+    result.params = params
     return result

@@ -157,16 +157,14 @@ def make_shardmap_dp_train_step(
         total = jax.lax.pmean(total, axis_name)
         return new_params, new_state, total, residual
 
-    from ..compat import shard_map_compat
-
     rep = P()
     sharded = P(axis_name)
-    smapped = shard_map_compat(
+    smapped = jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(rep, rep, sharded, rep, sharded if compressor else rep),
         out_specs=(rep, rep, rep, sharded if compressor else rep),
-        check_replication=False,
+        check_vma=False,
     )
     return jax.jit(smapped)
 

@@ -82,7 +82,6 @@ def test_error_feedback_converges_on_quadratic():
 import jax, numpy as np
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map_compat
 from repro.parallel.compression import Compressor
 mesh = jax.make_mesh((8,), ("x",))
 rng = np.random.default_rng(0)
@@ -95,10 +94,10 @@ def make_step():
         g_sync, new_res = comp.sync({"w": g}, {"w": residual[0]}, "x",
                                     strides=(1, 3))
         return w - 0.3 * g_sync["w"], new_res["w"][None]
-    return jax.jit(shard_map_compat(step, mesh=mesh,
-                                    in_specs=(P(), P("x"), P("x")),
-                                    out_specs=(P(), P("x")),
-                                    check_replication=False))
+    return jax.jit(jax.shard_map(step, mesh=mesh,
+                                 in_specs=(P(), P("x"), P("x")),
+                                 out_specs=(P(), P("x")),
+                                 check_vma=False))
 
 step = make_step()
 w = jnp.zeros(64)

@@ -391,6 +391,48 @@ def test_optimizer_crash_storm_backs_off(vgg_plan):
     assert ctrl._backoff_until == pytest.approx(3.0 + 4.0)
 
 
+def _raise_jax_runtime_error():
+    import jax
+
+    raise jax.errors.JaxRuntimeError("INTERNAL: device program failed")
+
+
+def _raise_jax_trace_error():
+    import jax
+
+    jax.jit(lambda x: int(x))(1.0)  # ConcretizationTypeError
+
+
+@pytest.mark.parametrize(
+    "fault", [_raise_jax_runtime_error, _raise_jax_trace_error],
+    ids=["runtime", "trace"],
+)
+def test_device_fault_propagates_from_optimizer(vgg_plan, fault):
+    """A JAX compile or runtime failure is not retried with another seed
+    nor logged as an ``:error`` record: it reaches the caller, so a broken
+    device path cannot hide behind the last-known-good plan."""
+    import jax
+
+    calls = []
+    ctrl = ReoptController(
+        VGG16, 8, hw=HW,
+        policy=ReoptPolicy(on_failure=True, replan_latency=1e-3,
+                           min_interval=0.0, replan_retries=2),
+        plan=vgg_plan,
+    )
+
+    def broken(warm=True):
+        calls.append(warm)
+        fault()
+
+    ctrl._run_optimizer = broken
+    with pytest.raises((jax.errors.JaxRuntimeError, jax.errors.JAXTypeError)):
+        ctrl.fail(_topo_pair(ctrl.topology), now=0.0)
+    assert len(calls) == 1
+    assert ctrl.n_optimizer_errors == 0 and ctrl._retry_nonce == 0
+    assert not any(r.trigger.endswith(":error") for r in ctrl.log)
+
+
 def test_replan_deadline_discards_slow_attempts(vgg_plan):
     import time
 

@@ -142,3 +142,19 @@ def test_xla_fallback_matches_kernel_mamba():
     np.testing.assert_allclose(
         np.asarray(y_fallback), np.asarray(y_kernel), rtol=1e-4, atol=1e-4
     )
+
+
+@pytest.mark.parametrize(
+    "backend,interpret", [("cpu", True), ("tpu", False), ("gpu", None)]
+)
+def test_ops_interpret_only_on_cpu(monkeypatch, backend, interpret):
+    """The wrappers interpret on the CPU, compile on the TPU, and refuse
+    any other backend rather than fall back to the interpreter."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="no lowering"):
+            ops._default_interpret()
+    else:
+        assert ops._default_interpret() is interpret

@@ -3,6 +3,7 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.core.routing import (
+    Route,
     RoutingTable,
     allreduce_routes,
     bandwidth_tax,
@@ -56,6 +57,97 @@ def test_allreduce_routes_follow_rings():
             assert r.path[0] == src and r.path[-1] == dst
             for a, b in zip(r.path[:-1], r.path[1:]):
                 assert (b - a) % 8 in (1, 3)  # every hop rides a ring edge
+
+
+def _eager_rings(groups):
+    """Reference: every group's ring routes spelled out in order, a later
+    group's route replacing an earlier one on a shared pair."""
+    routes = {}
+    for members, strides in groups:
+        n = len(members)
+        bt = coin_change_mod(n, strides)
+        for i in range(n):
+            for m, coins in bt.items():
+                path = [i]
+                for c in coins:
+                    path.append((path[-1] + c) % n)
+                routes[(members[i], members[(i + m) % n])] = [
+                    tuple(members[v] for v in path)
+                ]
+    return routes
+
+
+def _paths(rs):
+    return [r.path for r in rs]
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_lazy_ring_table_matches_eager_build(seed):
+    """Ring routes built on demand agree pair by pair, and in spelled-out
+    order, with the eager build; routes set or added on top win."""
+    import networkx as nx
+
+    rng = np.random.default_rng(seed)
+    n = 24
+    groups = [
+        (tuple(int(v) for v in rng.choice(n, size=k, replace=False)),
+         [1, 3] if k > 4 else [1])
+        for k in (12, 8, 5)
+    ]
+    ref = _eager_rings(groups)
+    mp_pair, extra = (0, 1), (2, 3)
+
+    def build(check=False):
+        table = RoutingTable()
+        for members, strides in groups:
+            table.add_rings(members, strides)
+        if check:
+            for s in range(n):
+                for t in range(n):
+                    assert _paths(table.get(s, t)) == ref.get((s, t), [])
+        table.set(*mp_pair, [Route(path=(0, 2, 1))])
+        table.add(*extra, (2, 5, 3))
+        return table
+
+    table = build(check=True)
+    ref[mp_pair] = [(0, 2, 1)]
+    ref[extra] = ref.get(extra, []) + [(2, 5, 3)]
+    assert [(p, _paths(rs)) for p, rs in table.routes.items()] == list(
+        ref.items()
+    )
+
+    # Dead links: routes that avoid them stay, the rest take a shortest
+    # path on what is left, and unreachable pairs drop out.
+    g = nx.MultiDiGraph()
+    g.add_nodes_from(range(n))
+    for p in ref.values():
+        for path in p:
+            g.add_edges_from(zip(path[:-1], path[1:]))
+    edges = sorted(set(g.edges()))
+    removed = {edges[i] for i in rng.choice(len(edges), size=6, replace=False)}
+    for e in removed:
+        while g.has_edge(*e):
+            g.remove_edge(*e)
+    simple = nx.DiGraph(g)
+    want = {}
+    for pair, paths in ref.items():
+        keep = [q for q in paths
+                if not any(h in removed for h in zip(q[:-1], q[1:]))]
+        if not keep:
+            try:
+                keep = [tuple(nx.shortest_path(simple, *pair))]
+            except nx.NetworkXNoPath:
+                continue
+        want[pair] = keep
+    after = build().rerouted(removed, g)
+    for pair in ref:
+        assert _paths(after.get(*pair)) == want.get(pair, [])
+    over = after.overlay()
+    over.add(4, 9, (4, 9))
+    assert _paths(over.get(4, 9)) == want.get((4, 9), []) + [(4, 9)]
+    assert _paths(after.get(4, 9)) == want.get((4, 9), [])
+    spelled = table.rerouted(removed, g).routes
+    assert [(p, _paths(rs)) for p, rs in spelled.items()] == list(want.items())
 
 
 def test_bandwidth_tax_direct_is_one():
