@@ -3,7 +3,8 @@
 Stateless: ``batch_for_step(step)`` derives every batch from (seed, step), so
 checkpoint/restart and elastic rescaling never need data-state checkpoints —
 restarting at step k regenerates exactly the batch stream from k.  A
-background prefetch thread keeps ``depth`` batches ready.
+background prefetch thread keeps ``depth`` batches ready; an error making a
+batch is raised by the ``next()`` that would have handed it out.
 """
 
 from __future__ import annotations
@@ -14,6 +15,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from .. import telemetry
 from ..configs.base import ArchConfig, ShapeSpec
 
 
@@ -51,30 +53,47 @@ def batch_for_step(spec: DataSpec, step: int) -> dict:
 
 
 class Prefetcher:
-    """Background-thread batch prefetch with bounded depth."""
+    """Background-thread batch prefetch with bounded depth.
+
+    Each ``next()`` is a ``data.wait`` span, tagged with the step it hands
+    out."""
 
     def __init__(self, spec: DataSpec, start_step: int, depth: int = 2):
         self.spec = spec
         self._q: queue.Queue = queue.Queue(maxsize=depth)
         self._stop = threading.Event()
         self._step = start_step
+        self._error: Exception | None = None
         self._thread = threading.Thread(target=self._worker, daemon=True)
         self._thread.start()
 
     def _worker(self):
         step = self._step
         while not self._stop.is_set():
-            batch = batch_for_step(self.spec, step)
+            try:
+                item = (step, batch_for_step(self.spec, step))
+            except Exception as e:  # handed to next(), which raises it
+                item = (step, e)
             while not self._stop.is_set():
                 try:
-                    self._q.put((step, batch), timeout=0.1)
+                    self._q.put(item, timeout=0.1)
                     break
                 except queue.Full:
                     continue
+            if isinstance(item[1], Exception):
+                return
             step += 1
 
     def next(self) -> tuple[int, dict]:
-        return self._q.get()
+        if self._error is not None:
+            raise self._error
+        with telemetry.span("data.wait") as rec:
+            step, batch = self._q.get()
+            rec.step = step
+        if isinstance(batch, Exception):
+            self._error = batch
+            raise batch
+        return step, batch
 
     def close(self):
         self._stop.set()

@@ -81,37 +81,35 @@ def _hidden_xent_chunked(x, head, targets, mask, chunk: int):
 
 def loss_fn(params, batch, cfg: ArchConfig, remat: str = "full",
             loss_chunk: int = 0, aux_weight: float = 0.01):
-    """Scalar training loss (+ metrics dict)."""
+    """Scalar training loss (+ metrics dict).  For transformer stacks the
+    LM head and the cross-entropy lie in the named scope ``head_loss``."""
     if cfg.family == "audio":
-        logits, aux = forward(params, batch, cfg, remat=remat)
         targets = batch["labels"]
         mask = jnp.ones(targets.shape, jnp.float32)
-        loss = _xent(logits, targets, mask)
-        return loss, {"xent": loss}
-
-    tokens = batch["tokens"]
-    targets = jnp.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1)
-    mask = jnp.concatenate(
-        [jnp.ones(tokens[:, 1:].shape, jnp.float32),
-         jnp.zeros(tokens[:, :1].shape, jnp.float32)], axis=1,
-    )
-    if loss_chunk > 0:
-        if cfg.family == "ssm" or cfg.family == "hybrid":
-            # recurrent stacks keep their own head; fall through to full CE
-            logits, aux = forward(params, batch, cfg, remat=remat)
-            loss = _xent(logits, targets, mask)
-        else:
-            x, aux = transformer.hidden_forward(
-                params, cfg, tokens=batch.get("tokens"),
-                image_embeds=batch.get("image_embeds"), remat=remat,
-            )
-            head = params.get("lm_head")
-            if head is None:
-                head = params["embed"].T
-            loss = _hidden_xent_chunked(x, head, targets, mask, loss_chunk)
     else:
+        tokens = batch["tokens"]
+        targets = jnp.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1)
+        mask = jnp.concatenate(
+            [jnp.ones(tokens[:, 1:].shape, jnp.float32),
+             jnp.zeros(tokens[:, :1].shape, jnp.float32)], axis=1,
+        )
+    if cfg.family == "ssm" or cfg.family == "hybrid":
+        # recurrent stacks keep their own head; always the full CE
         logits, aux = forward(params, batch, cfg, remat=remat)
         loss = _xent(logits, targets, mask)
+    else:
+        x, aux = transformer.hidden_forward(
+            params, cfg, tokens=batch.get("tokens"), frames=batch.get("frames"),
+            image_embeds=batch.get("image_embeds"), remat=remat,
+        )
+        with jax.named_scope("head_loss"):
+            head = transformer.head_weights(params)
+            if loss_chunk > 0 and cfg.family != "audio":
+                loss = _hidden_xent_chunked(x, head, targets, mask, loss_chunk)
+            else:
+                loss = _xent(jnp.einsum("bsd,dv->bsv", x, head), targets, mask)
+    if cfg.family == "audio":
+        return loss, {"xent": loss}
     total = loss + aux_weight * aux
     return total, {"xent": loss, "aux": aux}
 

@@ -102,6 +102,13 @@ def _cross_block_apply(blk, x, img, cfg):
     return h + y
 
 
+def head_weights(params):
+    """The (d_model, vocab) output projection: ``lm_head``, or the
+    transposed embedding where the two are tied."""
+    head = params.get("lm_head")
+    return params["embed"].T if head is None else head
+
+
 def forward(
     params,
     cfg: ArchConfig,
@@ -111,60 +118,26 @@ def forward(
     remat: str = "full",
 ):
     """Full-sequence forward -> (logits (B, S, V), aux_loss)."""
-    if cfg.family == "audio":
-        x = frames
-        S = x.shape[1]
-        mask = None
-    else:
-        x = constrain(
-            params["embed"][tokens].astype(jnp.dtype(cfg.activation_dtype)), "btd"
-        )
-        S = tokens.shape[1]
-        mask = None  # attention() builds/streams the mask per impl
-    positions = jnp.arange(S)[None, :]
-
-    def block_fn(carry, blk):
-        h, aux = carry
-        h2, a = _self_block_apply(blk, constrain(h, "btd"), cfg, mask, positions)
-        return (constrain(h2, "btd"), aux + a), None
-
-    block_fn = _remat(block_fn, remat)
-
-    if cfg.family == "vlm":
-        img = image_embeds.astype(x.dtype)
-
-        def super_fn(carry, blk):
-            inner_carry, _ = lax.scan(block_fn, carry, blk["self"])
-            h, aux = inner_carry
-            h = _cross_block_apply(blk["cross"], h, img, cfg)
-            return (h, aux), None
-
-        (x, aux), _ = lax.scan(_remat(super_fn, "none"), (x, jnp.float32(0.0)),
-                               params["blocks"])
-    else:
-        (x, aux), _ = lax.scan(block_fn, (x, jnp.float32(0.0)), params["blocks"])
-
-    x = L.rms_norm(x, params["final_norm"])
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed"].T
-    logits = jnp.einsum("bsd,dv->bsv", x, head)
-    return logits, aux
+    x, aux = hidden_forward(params, cfg, tokens=tokens, frames=frames,
+                            image_embeds=image_embeds, remat=remat)
+    return jnp.einsum("bsd,dv->bsv", x, head_weights(params)), aux
 
 
 def hidden_forward(params, cfg, tokens=None, frames=None, image_embeds=None,
                    remat: str = "full"):
-    """Like forward() but stops before the LM head (for chunked losses)."""
-    # Reuse forward's plumbing by temporarily removing the head projection:
-    # duplicated minimal body to avoid computing the big logits einsum.
+    """The stack up to the final norm, before the LM head -> (hidden
+    (B, S, D), aux_loss).  Named scopes ``embed`` and ``blocks`` mark the
+    embedding lookup and the layer stack in the compiled program."""
     if cfg.family == "audio":
         x = frames
         S = x.shape[1]
         mask = None
     else:
-        x = constrain(
-            params["embed"][tokens].astype(jnp.dtype(cfg.activation_dtype)), "btd"
-        )
+        with jax.named_scope("embed"):
+            x = constrain(
+                params["embed"][tokens].astype(jnp.dtype(cfg.activation_dtype)),
+                "btd",
+            )
         S = tokens.shape[1]
         mask = None  # attention() builds/streams the mask per impl
     positions = jnp.arange(S)[None, :]
@@ -175,18 +148,21 @@ def hidden_forward(params, cfg, tokens=None, frames=None, image_embeds=None,
         return (constrain(h2, "btd"), aux + a), None
 
     block_fn = _remat(block_fn, remat)
-    if cfg.family == "vlm":
-        img = image_embeds.astype(x.dtype)
+    with jax.named_scope("blocks"):
+        if cfg.family == "vlm":
+            img = image_embeds.astype(x.dtype)
 
-        def super_fn(carry, blk):
-            inner_carry, _ = lax.scan(block_fn, carry, blk["self"])
-            h, aux = inner_carry
-            h = _cross_block_apply(blk["cross"], h, img, cfg)
-            return (h, aux), None
+            def super_fn(carry, blk):
+                inner_carry, _ = lax.scan(block_fn, carry, blk["self"])
+                h, aux = inner_carry
+                h = _cross_block_apply(blk["cross"], h, img, cfg)
+                return (h, aux), None
 
-        (x, aux), _ = lax.scan(super_fn, (x, jnp.float32(0.0)), params["blocks"])
-    else:
-        (x, aux), _ = lax.scan(block_fn, (x, jnp.float32(0.0)), params["blocks"])
+            (x, aux), _ = lax.scan(super_fn, (x, jnp.float32(0.0)),
+                                   params["blocks"])
+        else:
+            (x, aux), _ = lax.scan(block_fn, (x, jnp.float32(0.0)),
+                                   params["blocks"])
     return L.rms_norm(x, params["final_norm"]), aux
 
 

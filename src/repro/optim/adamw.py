@@ -42,6 +42,10 @@ def adamw(
         return state
 
     def update(grads, state, params, step):
+        with jax.named_scope("adamw"):
+            return _update(grads, state, params, step)
+
+    def _update(grads, state, params, step):
         lr = lr_fn(step)
         t = (step + 1).astype(jnp.float32)
         c1 = 1.0 - b1**t

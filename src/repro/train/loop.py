@@ -2,15 +2,15 @@
 
 - checkpoint every N steps (atomic), resume from latest on start,
 - deterministic stateless data pipeline (restart-safe),
-- straggler detection: per-step wall time vs running median; slow steps are
-  counted and surfaced (on a real pod this feeds the backup-worker /
-  TopoOpt link-repair path),
+- straggler detection: each step's ``train.step`` span (dispatch and loss
+  read) vs the median of the last 50; slow steps are counted and surfaced
+  (on a real pod this feeds the backup-worker / TopoOpt link-repair path),
+- a step after the first whose dispatch built an executable is logged,
 - failure injection hook for tests (``fail_at``) proving restart works.
 """
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Any
 
@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry
 from ..checkpoint.ckpt import latest_step, load_checkpoint, prune_checkpoints, save_checkpoint
 from ..configs.base import ArchConfig, ShapeSpec
 from ..data.pipeline import DataSpec, Prefetcher
@@ -80,24 +81,29 @@ def train(
 
     data = Prefetcher(DataSpec(cfg=cfg, shape=shape, seed=seed), start_step)
     result = TrainResult(final_step=start_step)
-    step_times: list[float] = []
 
     try:
         step = start_step
         while step < total_steps:
             got_step, batch = data.next()
             assert got_step == step, f"pipeline desync {got_step} != {step}"
-            t0 = time.perf_counter()
-            with mesh:
-                params, opt_state, metrics = jitted(
-                    params, opt_state, batch, jnp.int32(step)
-                )
+            with (jax.profiler.StepTraceAnnotation("train", step_num=step),
+                  telemetry.span("train.step", step) as rec):
+                with mesh:
+                    params, opt_state, metrics = jitted(
+                        params, opt_state, batch, jnp.int32(step)
+                    )
+                with telemetry.span("train.loss_read", step):
+                    loss = float(metrics["loss"])
             result.metrics = metrics
-            loss = float(metrics["loss"])
-            dt = time.perf_counter() - t0
-            step_times.append(dt)
-            med = float(np.median(step_times[-50:]))
-            if len(step_times) > 5 and dt > straggler_factor * med:
+            built = jitted.last.counts.get("train.compiles", 0)
+            if built and step > start_step:
+                logger(f"[loop] step {step} built {built} executable(s)")
+            dt = rec.seconds
+            done = step - start_step + 1
+            med = float(np.median(
+                [r.seconds for r in telemetry.recent("train.step", min(done, 50))]))
+            if done > 5 and dt > straggler_factor * med:
                 result.straggler_steps += 1
                 logger(f"[loop] straggler at step {step}: {dt:.3f}s vs median {med:.3f}s")
 

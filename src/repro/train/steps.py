@@ -8,6 +8,9 @@ Two execution styles:
 * ``shard_map_dp`` (examples/tests): explicit data-parallel trainer whose
   gradient sync is the paper's multi-ring TotientPerms AllReduce
   (core.collectives), matching the NCCL integration of §6.
+
+Both return the jitted step inside :class:`RecordedStep`, which records each
+call's host dispatch and the executables it built (``repro.telemetry``).
 """
 
 from __future__ import annotations
@@ -18,6 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import telemetry
 from ..configs.base import ArchConfig, ShapeSpec, cache_specs, input_specs
 from ..core.collectives import topoopt_psum_fn
 from ..models import lm
@@ -29,6 +33,33 @@ from ..parallel.sharding import (
     opt_state_sharding,
     param_sharding,
 )
+
+
+class RecordedStep:
+    """A jitted train step whose every call is a ``train.dispatch`` span:
+    the host's work to launch the step (flattening the arguments, copying
+    host arrays to the device, and compiling where the call needs a new
+    executable).  The span carries the ``train.compiles`` counter: the
+    entries the call added to the jit's cache, each an executable compiled
+    or loaded from the persistent cache, or now and then a new signature
+    of one it had (the same sharding spelled another way).  ``last`` is the
+    newest call's record.  Every other attribute (``lower``, ``trace``, ...)
+    is the jit's own; donation is the jit's."""
+
+    def __init__(self, jitted):
+        self._jitted = jitted
+        self.last: telemetry.Span | None = None
+
+    def __call__(self, *args, **kwargs):
+        before = self._jitted._cache_size()
+        with telemetry.span("train.dispatch") as self.last:
+            out = self._jitted(*args, **kwargs)
+            telemetry.count("train.compiles",
+                            self._jitted._cache_size() - before)
+        return out
+
+    def __getattr__(self, name):
+        return getattr(self._jitted, name)
 
 
 def install_activation_policy(plan: ShardingPlan, mesh: Mesh) -> None:
@@ -87,8 +118,10 @@ def jit_train_step(
 ):
     """jit(train_step) with in/out shardings derived from the plan.
 
-    Returns (jitted_fn, (param_specs, opt_specs, batch_fn)) where batch_fn
-    maps a ShapeSpec to that cell's batch ShapeDtypeStructs."""
+    Returns (step, (param_specs, opt_specs, param_shardings,
+    opt_shardings, batch_fn)): ``step`` is the jit as a
+    :class:`RecordedStep`; batch_fn maps a ShapeSpec to that cell's batch
+    shardings."""
     install_activation_policy(plan, mesh)
     p_specs = lm.param_specs(cfg)
     o_specs = jax.eval_shape(optimizer.init, p_specs)
@@ -107,7 +140,7 @@ def jit_train_step(
         out_shardings=(p_sh, o_sh, None),
         donate_argnums=(0, 1) if donate else (),
     )
-    return jitted, (p_specs, o_specs, p_sh, o_sh, batch_sh)
+    return RecordedStep(jitted), (p_specs, o_specs, p_sh, o_sh, batch_sh)
 
 
 # ---------------------------------------------------------------------------
@@ -166,7 +199,7 @@ def make_shardmap_dp_train_step(
         out_specs=(rep, rep, rep, sharded if compressor else rep),
         check_vma=False,
     )
-    return jax.jit(smapped)
+    return RecordedStep(jax.jit(smapped))
 
 
 def init_compressor_residual(compressor, params, mesh, axis_name="data"):
