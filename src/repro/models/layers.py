@@ -13,6 +13,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
+
+from .. import telemetry
 
 
 def truncated_normal(key, shape, scale, dtype):
@@ -159,14 +162,122 @@ def _chunked_sdpa(qg, k, v, *, causal: bool, window: int, chunk: int):
     return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(qg.dtype)
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _fused_attention(x, p, cfg, causal: bool, window: int):
+    """The fused attention kernel (``kernels.ops.fused_attention``) placed
+    per shard, as a function of sequence-minor (q, k, v); None where it
+    cannot run.  x: (B, S, d_model), the layer's input; p its weights.
+
+    It runs on the TPU, for a sequence that is a multiple of the kernel's
+    block, where each shard can attend on its own: inside ``shard_map``;
+    under ``shard_map`` over the mesh that the arrays' types name (a mesh
+    of explicit axes), with x's batch sharding and the weights whole; else
+    over the activation policy's mesh (``act_sharding.get_policy``), the
+    batch over its data axes and heads over ``tp``.  A sequence or
+    feature dimension sharded, or a batch or head count the mesh does not
+    divide, keeps the XLA path: a Pallas call left to GSPMD runs
+    replicated and would gather the activations onto every device."""
+    from ..kernels import ops
+    from ..parallel.act_sharding import get_policy
+
+    B, S, _ = x.shape
+    if not _on_tpu() or S % ops.SEQ_BLOCK:
+        return None
+    kernel = partial(ops.fused_attention, causal=causal, window=window)
+    context = jax.sharding.get_abstract_mesh()
+    if context.manual_axes:
+        # inside shard_map: the arrays are this device's shards already
+        return kernel if set(context.manual_axes) == set(context.axis_names) else None
+    typed = jax.typeof(x).sharding
+    if AxisType.Explicit in typed.mesh.axis_types:
+        b, s, d = (tuple(typed.spec) + (None,) * 3)[:3]
+        whole = all(a is None for w in ("wq", "wk", "wv")
+                    for a in jax.typeof(p[w]).sharding.spec)
+        if s is not None or d is not None or not whole:
+            return None
+        mesh, spec = typed.mesh, P(b, None, None, None)
+    else:
+        pol = get_policy()
+        if pol is not None and pol.seq is not None:
+            return None
+        if pol is None or pol.mesh is None:
+            return kernel
+
+        def size(axes):
+            axes = () if axes is None else (axes,) if isinstance(axes, str) else axes
+            return math.prod(pol.mesh.shape[a] for a in axes)
+
+        tp = size(pol.tp)
+        if B % size(pol.dp) or cfg.n_heads % tp or cfg.n_kv_heads % tp:
+            return None
+        # Auto axes, as the policy's constraints are hints: the output's
+        # type carries no sharding, as the other activations' do not.
+        mesh = Mesh(pol.mesh.devices, pol.mesh.axis_names,
+                    axis_types=(AxisType.Auto,) * len(pol.mesh.axis_names))
+        spec = P(pol.dp, pol.tp, None, None)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)
+
+
+def _fused_self_attention(p, x, cfg, kernel, positions, use_rope):
+    """Self-attention through the fused kernel.  q, k and v are made with
+    the sequence minor, (B, heads, head_dim, S), the layout the kernel
+    reads and one that tiles without padding; RoPE and the 1/sqrt(d) scale
+    are applied together in float32 and rounded once.  Returns
+    (B, S, d_model)."""
+    hd = cfg.hd
+    B, S, d = x.shape
+
+    def heads(w, n):
+        return jnp.einsum("bsd,de->bes", x, w).reshape(B, n, hd, S)
+
+    q, k, v = (heads(p["wq"], cfg.n_heads), heads(p["wk"], cfg.n_kv_heads),
+               heads(p["wv"], cfg.n_kv_heads))
+    scale = 1.0 / math.sqrt(hd)
+    if use_rope:
+        if positions is None:
+            positions = jnp.arange(S)[None, :]
+        q = _rope_seq_minor(q, positions, cfg.rope_theta, scale)
+        k = _rope_seq_minor(k, positions, cfg.rope_theta)
+    else:
+        q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    out = kernel(q, k, v)  # (B, H, S, hd)
+    return jnp.einsum("bhsk,hkd->bsd", out, p["wo"].reshape(cfg.n_heads, hd, d))
+
+
+def _rope_seq_minor(x, positions, theta: float, scale: float = 1.0):
+    """:func:`apply_rope` on x: (B, H, D, S), times ``scale``."""
+    d = x.shape[-2]
+    freqs = rope_frequencies(d, theta)  # (D/2,)
+    angles = freqs[:, None] * positions[:, None, None, :].astype(jnp.float32)
+    sin, cos = jnp.sin(angles), jnp.cos(angles)  # (B, 1, D/2, S)
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-2)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-2)
+    return (out * scale).astype(x.dtype)
+
+
 def attention(p, x, cfg, *, mask=None, causal=True, window=0, positions=None,
               kv_x=None, use_rope=True):
     """Self- or cross-attention over full sequences (train / prefill).
 
     x: (B, S, d_model); kv_x: (B, T, d_model) for cross-attention.
-    ``mask`` overrides (causal, window) for the naive path.
-    Returns (B, S, d_model).
+    ``mask`` overrides (causal, window) for the naive path.  Self-attention
+    with no explicit mask runs the fused kernel where
+    :func:`_fused_attention` finds it can; every other case, and the
+    fused kernel's refusals, take the XLA path that
+    ``ModelOptions.attention_impl`` picks.  Each call traced counts
+    ``attention.fused`` or ``attention.xla`` on the innermost open
+    ``repro.telemetry`` span.  Returns (B, S, d_model).
     """
+    if kv_x is None and mask is None and (causal or not window):
+        fused = _fused_attention(x, p, cfg, causal, window)
+        if fused is not None:
+            telemetry.count("attention.fused")
+            return _fused_self_attention(p, x, cfg, fused, positions, use_rope)
+    telemetry.count("attention.xla")
     from ..parallel.options import get_options
 
     hd = cfg.hd

@@ -16,7 +16,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 import jax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 
 @dataclass(frozen=True)
@@ -24,6 +24,7 @@ class ActivationPolicy:
     dp: tuple | str | None  # axes for the batch dim
     tp: str | None  # axis for feature/head dims
     seq: str | None = None  # axis for the sequence dim (sequence parallelism)
+    mesh: Mesh | None = None  # the mesh the axes name
 
 
 _POLICY: ActivationPolicy | None = None

@@ -69,6 +69,7 @@ def install_activation_policy(plan: ShardingPlan, mesh: Mesh) -> None:
             dp=plan.dp_axes(mesh),
             tp="model" if "model" in mesh.axis_names else None,
             seq="model" if plan.seq_parallel else None,
+            mesh=mesh,
         )
     )
 

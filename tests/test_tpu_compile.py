@@ -25,7 +25,9 @@ import chip_smoke
 from repro.configs.base import ShapeSpec, get_config, input_specs
 from repro.core.planeval_jax import DEFAULT_TEMPER_LADDER, _grid_program, _require_jax
 from repro.core.workloads import DLRM
+from repro.models import layers
 from repro.kernels.embedding_bag import embedding_bag
+from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mamba_scan import mamba_scan
 from repro.kernels.moe_gmm import moe_gmm
@@ -65,6 +67,14 @@ def _specs(one_chip, *shapes):
     return [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
 
 
+def _fused_fwd(q, k, v):
+    return ops.fused_attention(q, k, v, causal=True, interpret=False)
+
+
+def _fused_loss(q, k, v):
+    return jnp.sum(_fused_fwd(q, k, v).astype(jnp.float32))
+
+
 def _kernel_cases():
     bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
     lm = get_config("minicpm-2b")
@@ -78,9 +88,16 @@ def _kernel_cases():
     # table (a chip holds 16 GB, the paper's tables 327 GB).
     tables = (DLRM.n_tables, 100_000, DLRM.table_dim)
     bags = (DLRM.batch_per_gpu, DLRM.n_tables, 4)
+    # The training cell's attention: batch 2 of 1024 tokens, 36 heads of
+    # 64, the sequence minor.
+    cell = (2, lm.n_heads, lm.hd, 1024)
     return {
         "flash_attention-minicpm-2b": (
             flash_attention, [(q, bf16), (kv, bf16), (kv, bf16)]),
+        "fused_attention-fwd-minicpm-2b": (
+            jax.jit(_fused_fwd), [(cell, bf16)] * 3),
+        "fused_attention-bwd-minicpm-2b": (
+            jax.jit(jax.grad(_fused_loss, argnums=(0, 1, 2))), [(cell, bf16)] * 3),
         "moe_gmm-qwen3-moe-30b-a3b": (
             moe_gmm, [((moe.n_experts, 256, moe.d_model), bf16),
                       ((moe.n_experts, moe.d_model, moe.d_ff), bf16)]),
@@ -158,6 +175,46 @@ def test_train_step_fits_one_chip(topo, one_chip):
         compiled = jitted.lower(
             place(p_specs, p_sh), place(o_specs, o_sh), batch, step
         ).compile()
+    used = compiled.memory_analysis()
+    total = used.argument_size_in_bytes + used.temp_size_in_bytes
+    assert total <= V5E_HBM - 1.5 * 2**30, total / 2**30
+
+
+def test_train_step_with_fused_attention_fits_one_chip(topo, one_chip,
+                                                       monkeypatch):
+    """The same step as the chip runs it: attention through the fused
+    kernels (the choice of path sees this process's CPU, so the test takes
+    the TPU's branch itself).  It compiles, holds the kernels, and fits as
+    the XLA path does."""
+    monkeypatch.setattr(layers, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    base = get_config(chip_smoke.MODEL)
+    cfg = dataclasses.replace(base, n_layers=chip_smoke.TRAIN_LAYERS)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    jitted, (p_specs, o_specs, p_sh, o_sh, _) = jit_train_step(
+        cfg, adamw(wsd(3e-3, 5)), ShardingPlan(fsdp=False, remat="full"), mesh
+    )
+
+    def place(tree, shardings):
+        return jax.tree.map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            tree, shardings,
+        )
+
+    shape = ShapeSpec("smoke", chip_smoke.TRAIN_SEQ, chip_smoke.TRAIN_BATCH,
+                      "train")
+    batch = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        input_specs(cfg, shape),
+    )
+    step = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    with jax.enable_x64(False):
+        compiled = jitted.lower(
+            place(p_specs, p_sh), place(o_specs, o_sh), batch, step
+        ).compile()
+    # the forward kernel in the forward and in the remat of the backward,
+    # and the backward kernel
+    assert compiled.as_text().count("tpu_custom_call") >= 3
     used = compiled.memory_analysis()
     total = used.argument_size_in_bytes + used.temp_size_in_bytes
     assert total <= V5E_HBM - 1.5 * 2**30, total / 2**30
