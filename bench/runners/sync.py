@@ -14,9 +14,14 @@ from __future__ import annotations
 from bench.runners import train
 
 MUST_PASS = train.MUST_PASS
+# Limits of this cell, set from its own readings on four chips (PERF.md):
+# its int8 control reads a smaller loss gap than the one-chip cell's.
+LIMITS = {"loss_gap": 7e-5, "grad_gap": 3e-3, "change_gap": 0.02}
 
 
 class Runner(train.Runner):
+    limits = LIMITS
+
     def build(self):
         import jax
         import numpy as np

@@ -3,8 +3,15 @@
 import argparse
 import dataclasses
 import json
+import os
+import subprocess
+import sys
+from pathlib import Path
+from unittest import mock
 
 from bench import core
+
+ROOT = Path(__file__).resolve().parents[2]
 
 # The smallest model at which bfloat16 rounding reads well under the
 # limits set at the cells' own sizes (a width of 64 does not).
@@ -48,18 +55,31 @@ def small_cell(name: str) -> core.Cell:
     return dataclasses.replace(cell, config=cfg, traffic=traffic)
 
 
-def run_cell(cell: core.Cell, seed: int, seconds: float = 0.5, trace: int = 0,
-             monkeypatch=None):
+def run_cell(cell: core.Cell, seed: int, seconds: float = 0.5, trace: int = 0):
     """The harness's whole run after its look for a chip: set-up, window,
-    check.  Returns the result object it would print."""
+    check, with a stand-in peak for the CPU.  Returns the result object it
+    would print."""
     import jax
 
     from bench import run
 
-    if monkeypatch is not None:
-        monkeypatch.setattr(core, "load_peaks", lambda kind, *a: {"bf16_flops": 1e12})
     args = argparse.Namespace(workload=cell.name, seed=seed, seconds=seconds,
                               trace=trace)
-    result, _ = run.execute(cell, jax.devices()[:cell.chips], args,
-                            core.load_runner(cell))
+    with mock.patch.object(core, "load_peaks",
+                           lambda kind, *a: {"bf16_flops": 1e12}):
+        result, _ = run.execute(cell, jax.devices()[:cell.chips], args,
+                                core.load_runner(cell))
     return result
+
+
+def run_on_devices(script: str, *argv: str, devices: int) -> dict:
+    """``script`` in a fresh Python on ``devices`` virtual CPU devices, with
+    the repo's root and ``src`` on its path.  Returns the JSON object of its
+    last line of output."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
+    head = f"import sys; sys.path[:0] = [{str(ROOT)!r}, {str(ROOT / 'src')!r}]\n"
+    proc = subprocess.run([sys.executable, "-c", head + script, *argv], cwd=ROOT,
+                          env=env, capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])

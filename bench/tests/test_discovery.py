@@ -11,6 +11,8 @@ import pytest
 
 from bench import core
 
+from .helpers import run_on_devices
+
 ROOT = Path(__file__).resolve().parents[2]
 
 
@@ -96,3 +98,66 @@ def test_refuses_a_checkout_with_only_the_benchmark(tmp_path):
     proc = _run(tmp_path, {"PYTHONPATH": ""})
     assert proc.returncode != 0
     assert proc.stdout.strip() == ""
+
+
+def _cells(bench):
+    return [w["name"] for w in bench["workloads"]]
+
+
+def _end_to_end(bench, cell):
+    return [m["name"] for m in bench["end_to_end"] if core._reports(m, cell)]
+
+
+def test_every_cell_reports_set_up_and_one_other_end_to_end_metric():
+    bench = core.load_benchmark()
+    for cell in _cells(bench):
+        names = _end_to_end(bench, cell)
+        assert "setup_s" in names, cell
+        assert len(names) == 2, (cell, names)
+
+
+def test_every_per_layer_metric_moves_what_its_cells_report():
+    bench = core.load_benchmark()
+    cells = _cells(bench)
+    for m in bench["per_layer"]:
+        listed = m.get("workloads", cells)
+        assert listed and set(listed) <= set(cells), m["name"]
+        for cell in listed:
+            assert m["moves"] in _end_to_end(bench, cell), (m["name"], cell)
+
+
+def test_at_most_half_the_cells_ask_for_four_chips():
+    bench = core.load_benchmark()
+    chips = [w["chips"] for w in bench["workloads"]]
+    assert set(chips) <= {1, 4}
+    assert chips.count(4) <= max(1, len(chips) // 2)
+
+
+TRACED = """
+import dataclasses, json, sys
+from bench import core
+from bench.tests.helpers import run_cell, small_cell
+
+name = sys.argv[1]
+listed = core.load_cell(name)
+# A name of its own keeps its trace directory apart from other tests' runs.
+cell = dataclasses.replace(small_cell(name), name=name + ".discovery",
+                           per_layer=listed.per_layer,
+                           end_to_end=listed.end_to_end)
+res = run_cell(cell, 2**31 + 53, seconds=1.0, trace=1)
+print(json.dumps({"correct": res["correct"], "count": res["device"]["count"],
+                  "metrics": res["metrics"]}))
+"""
+
+
+@pytest.mark.parametrize("name", _cells(core.load_benchmark()))
+def test_traced_run_reads_every_host_metric(name):
+    """A small traced run of each cell on the CPU, on as many virtual
+    devices as the cell asks for chips: every per-layer metric that the
+    host's spans or counters give has a value.  Those read from the
+    device's trace find no device there; the chip shows them."""
+    listed = core.load_cell(name)
+    res = run_on_devices(TRACED, name, devices=listed.chips)
+    assert res["correct"] is True and res["count"] == listed.chips
+    host = [m["name"] for m in listed.per_layer if m["source"] != "device_trace"]
+    assert host and set(host) <= set(res["metrics"]), (host, res["metrics"])

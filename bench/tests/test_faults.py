@@ -32,9 +32,8 @@ def _wrap_train_step(monkeypatch, fault):
     monkeypatch.setattr(steps, "jit_train_step", broken)
 
 
-def test_train_sound_run_is_correct(monkeypatch):
-    res = run_cell(small_cell("train.minicpm-2b.s1024"), 2**31 + 23,
-                   monkeypatch=monkeypatch)
+def test_train_sound_run_is_correct():
+    res = run_cell(small_cell("train.minicpm-2b.s1024"), 2**31 + 23)
     assert res["correct"] is True
     assert list(res)[-1] == "compared"
 
@@ -42,8 +41,7 @@ def test_train_sound_run_is_correct(monkeypatch):
 @pytest.mark.parametrize("fault", ["unchanged", "half_batch", "loss_altered"])
 def test_train_fault_is_caught(monkeypatch, fault):
     _wrap_train_step(monkeypatch, fault)
-    res = run_cell(small_cell("train.minicpm-2b.s1024"), 2**31 + 29,
-                   monkeypatch=monkeypatch)
+    res = run_cell(small_cell("train.minicpm-2b.s1024"), 2**31 + 29)
     assert res["correct"] is False
 
 
@@ -73,6 +71,5 @@ def test_admit_fault_is_caught(monkeypatch, fault):
     else:
         monkeypatch.setattr(online.JobSetController, "replan",
                             lambda self, now, trigger: None)
-    res = run_cell(small_cell("admit.shared432.churn"), 2**31 + 31,
-                   monkeypatch=monkeypatch)
+    res = run_cell(small_cell("admit.shared432.churn"), 2**31 + 31)
     assert res["correct"] is False

@@ -25,8 +25,8 @@ def _cell():
                                end_to_end=listed.end_to_end)
 
 
-def test_traced_run_reads_the_program_spans(monkeypatch):
-    res = run_cell(_cell(), 2**31 + 43, trace=1, monkeypatch=monkeypatch)
+def test_traced_run_reads_the_program_spans():
+    res = run_cell(_cell(), 2**31 + 43, trace=1)
     m = res["metrics"]
     assert set(READ) <= set(m)
     assert m["compiles.train"] == {"value": 0.0, "unit": "executables"}
@@ -48,7 +48,7 @@ def test_a_window_step_of_another_length_reads_a_compile(monkeypatch):
         return {"tokens": b["tokens"][:, :64]} if step == at else b
 
     monkeypatch.setattr(pipeline, "batch_for_step", batch)
-    res = run_cell(cell, 2**31 + 47, trace=1, monkeypatch=monkeypatch)
+    res = run_cell(cell, 2**31 + 47, trace=1)
     assert res["metrics"]["compiles.train"]["value"] >= 1
 
 

@@ -3,24 +3,13 @@ and a run with its timed path broken underneath is not: the gradient
 exchange between chips left out, the state returned unchanged, or half of
 the batch left out."""
 
-import json
-import os
-import subprocess
-import sys
-from pathlib import Path
-
 import pytest
 
-ROOT = Path(__file__).resolve().parents[2]
+from .helpers import run_on_devices
 
 SCRIPT = """
 import json, sys
-sys.path.insert(0, {src!r}); sys.path.insert(0, {root!r})
 from bench.tests.helpers import run_cell, small_cell
-
-class Patch:
-    def setattr(self, obj, name, value):
-        setattr(obj, name, value)
 
 import repro.train.steps as steps
 mode = sys.argv[1]
@@ -43,20 +32,13 @@ elif mode in ("unchanged", "half_batch"):
         return step
 
     steps.make_shardmap_dp_train_step = broken
-res = run_cell(small_cell("sync.minicpm-2b-dp4.ring"), 2**31 + 43, seconds=0.3,
-               monkeypatch=Patch())
-print(json.dumps({{"correct": res["correct"], "count": res["device"]["count"]}}))
+res = run_cell(small_cell("sync.minicpm-2b-dp4.ring"), 2**31 + 43, seconds=0.3)
+print(json.dumps({"correct": res["correct"], "count": res["device"]["count"]}))
 """
 
 
 def _run(mode):
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               XLA_FLAGS="--xla_force_host_platform_device_count=4")
-    code = SCRIPT.format(src=str(ROOT / "src"), root=str(ROOT))
-    proc = subprocess.run([sys.executable, "-c", code, mode], env=env,
-                          capture_output=True, text=True, timeout=300)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return run_on_devices(SCRIPT, mode, devices=4)
 
 
 def test_sound_run_is_correct():
